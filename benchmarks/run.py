@@ -25,6 +25,8 @@ import traceback
 
 
 def main() -> None:
+    import repro
+    repro.use_compile_cache()
     from benchmarks import (bench_churn, bench_coarse, bench_contracts,
                             bench_divergence, bench_ingest, bench_latency,
                             bench_recall, bench_replication, bench_roofline,

@@ -27,7 +27,6 @@ Public surface:
   query       — batched deterministic query engine: vmapped HNSW, planner,
                 shard fan-out (DESIGN.md §4)
   distributed — pod-scale sharded memory over shard_map (DESIGN.md §2)
-  compat      — version-bridging shims over moved JAX APIs
 
 Most-used entry points (each docstring states the contract it promises):
   replay / bulk_apply      — Apply(S_0, {C_i}); bulk form is hash-identical

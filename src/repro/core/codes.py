@@ -27,7 +27,7 @@ the touched rows; when params do drift it falls back to a full rebuild that
 is bit-identical to ``build`` by construction (tests/test_codes.py proves
 ``refresh == build`` over randomized six-opcode logs).
 
-Coarse scoring (kernels/qcoarse) ranks by an int32-weighted dot against the
+Coarse scoring (``search.coarse_search``) ranks by an int32-weighted dot against the
 codes; re-ranking the survivors with the exact wide Q16.16 scores restores
 bit-exactness whenever the candidate set covers the exact top-k — in
 particular, ``ef_coarse >= live_count`` makes the served answer equal
@@ -36,8 +36,9 @@ coverage-implies-bit-exact contract the conformance suite pins).
 
 Range analysis: boundary-normalized rows satisfy |raw| <= 2^16, so
 dev <= 2^17, e <= 11, scale <= 2^11, and a query weight
-|w_j| = |(q_j - offset_j) * scale_j| <= 2^28 = ``W_BOUND`` — the bound the
-qcoarse kernel's int32 limb planes rely on (see kernels/qcoarse/kernel.py).
+|w_j| = |(q_j - offset_j) * scale_j| <= 2^28 = ``W_BOUND``. Clipping to it
+keeps every weight an int32, the operand width of the coarse scan's exact
+int8 digit-plane dot (``limbs.exact_dot``, kernels/qgemm).
 """
 from __future__ import annotations
 
@@ -56,7 +57,7 @@ from repro.core.state import MemoryState
 
 # smallest e with 127 * 2^e >= dev, searched over e in [0, MAX_EXP)
 MAX_EXP = 16
-# |query weight| bound for boundary-normalized inputs (kernel exactness)
+# |query weight| bound for boundary-normalized inputs (keeps weights int32)
 W_BOUND = 1 << 28
 
 METRIC_L2 = "l2"
@@ -201,8 +202,8 @@ def query_weights(queries_raw: jax.Array, table: CodeTable, metric: str
       dot: -<q, offset + c*scale>      = const - S_i,
            w_j = q_j * scale_j
 
-    Computed in int64 then clipped to +-W_BOUND so the qcoarse limb planes
-    stay int32-exact (boundary-normalized inputs never reach the clip).
+    Computed in int64 then clipped to +-W_BOUND so every weight is an int32
+    (boundary-normalized inputs never reach the clip).
     """
     q = queries_raw.astype(jnp.int64)
     s = table.scale.astype(jnp.int64)[None, :]

@@ -33,7 +33,6 @@ from repro.core import machine, search
 from repro.core.commands import NOP, CommandLog
 from repro.core.hnsw import splitmix64
 from repro.core.state import MemoryState, init_state
-from repro.core import compat
 
 INF = search.INF
 
@@ -175,7 +174,7 @@ def distributed_replay(mesh: Mesh, axis: str, state: MemoryState,
     hash-routed, so shards never contend)."""
     specs = state_specs(axis, state.contract_name)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(specs, _log_specs(axis)),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(specs, _log_specs(axis)),
              out_specs=specs, check_vma=False)
     def _replay(local_state: MemoryState, local_log: CommandLog) -> MemoryState:
         local_log = jax.tree.map(lambda a: a[0], local_log)  # drop shard dim
@@ -284,7 +283,7 @@ def distributed_hnsw_search(mesh: Mesh, axis: str, state: MemoryState,
 
     from repro.core import query as query_lib  # lazy: query imports us lazily
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(specs, qspec),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(specs, qspec),
              out_specs=(out_spec, out_spec), check_vma=False)
     def _search(local_state: MemoryState, q: jax.Array):
         local = _to_local(local_state)
@@ -383,7 +382,7 @@ def distributed_search(mesh: Mesh, axis: str, state: MemoryState,
     qspec = P(query_axis, None)
     out_spec = P(query_axis, None)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(specs, qspec),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(specs, qspec),
              out_specs=(out_spec, out_spec), check_vma=False)
     def _search(local_state: MemoryState, q: jax.Array):
         ids, scores = search.exact_search(

@@ -36,7 +36,7 @@ import numpy as np
 from repro.core.state import MemoryState
 
 # large sentinel distance: safely above any real wide score, well below int64 max
-INF = jnp.int64(1) << 62
+INF = 1 << 62  # a Python int: importing this module creates no device array
 
 
 # --------------------------------------------------------------------------- #

@@ -10,15 +10,23 @@ unchanged to the wide domain.
 Used by fixedpoint.qdot_q32 (exact Q32.32 dot products) and validated against
 Python bigints in tests/test_limbs.py. Throughput is ~10 int ops per MAC —
 the paper's anticipated cost of the "enterprise" contract.
+
+The second half builds the substrate's scoring dot product the same way:
+exact int64 sums of products from int8 digit planes, the one integer matmul
+a TPU runs natively (its compiler refuses an s64 ``dot``). ``exact_dot`` is
+the XLA form and ``kernels/qgemm`` the Pallas form of one decomposition.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from functools import partial
+from typing import Callable, List, Tuple
 
 import jax
 import jax.numpy as jnp
 
-_MASK32 = jnp.uint64(0xFFFFFFFF)
+# a Python int, not a jnp scalar: importing the package must not create a
+# device array (that would initialize a backend and claim the chip)
+_MASK32 = 0xFFFFFFFF
 
 # A wide value is a tuple of 4 uint32 arrays (lo → hi limbs), two's complement.
 Wide = Tuple[jax.Array, jax.Array, jax.Array, jax.Array]
@@ -158,3 +166,103 @@ def q32_dot_to_q32(a: jax.Array, b: jax.Array, axis: int = -1) -> jax.Array:
     minv = jnp.int64(-(2**63))
     pos_overflow = (l3 >> jnp.uint32(31)) == 0
     return jnp.where(ok, val, jnp.where(pos_overflow, maxv, minv))
+
+
+# --------------------------------------------------------------------------- #
+# exact integer dot products from int8 digit planes
+# --------------------------------------------------------------------------- #
+#
+# A w-byte integer x is written as w signed base-256 digits plus a constant:
+#
+#     x = sum_i t_i * 256^i + bias(w),   t_i in [-128, 127] (int8),
+#
+# with bias(w) = 0x80 in every byte but the top one (0 for int8). The digits
+# are the bytes of x ^ bias, each read as a signed byte, so the split never
+# overflows and covers the whole dtype range. For x = X + Cx, y = Y + Cy:
+#
+#     sum_k x_k y_k = sum_s 256^s P_s + Cy sum_k x_k + Cx sum_k y_k - D Cx Cy,
+#     P_s = sum_{i+j=s} sum_k t_i,k u_j,k
+#
+# Each P_s is a sum of int8 x int8 products accumulated in int32: at most
+# m = min(digits of x, digits of y) products of magnitude <= 2^14 per k, so
+# |P_s| <= m * 2^14 * D, which fits int32 while m * D < 2^17 (D < 2^15 for
+# two int32 operands). The combination runs in int64, whose wrap-around is
+# arithmetic mod 2^64 like the int64 einsum it replaces: the two agree
+# bit for bit on every input.
+
+DIGIT_PRODUCT_BOUND = 1 << 17  # m * D must stay below this (see above)
+
+
+def digit_bias(dtype) -> int:
+    """The constant C with ``x == sum_i digits(x)[i] * 256**i + C``."""
+    return sum(0x80 << (8 * i) for i in range(jnp.dtype(dtype).itemsize - 1))
+
+
+def digits(x: jax.Array) -> List[jax.Array]:
+    """Signed int8 digits of an integer array, least significant first."""
+    n = jnp.dtype(x.dtype).itemsize
+    if n == 1:
+        return [x.astype(jnp.int8)]
+    bits = 8 * n
+    u = x ^ digit_bias(x.dtype)
+    return [((u << (bits - 8 - 8 * i)) >> (bits - 8)).astype(jnp.int8)
+            for i in range(n)]
+
+
+def check_digit_bound(a_dtype, b_dtype, dim: int) -> None:
+    """Raise unless the int32 digit planes of an ``[*, dim]`` dot are exact."""
+    m = min(jnp.dtype(a_dtype).itemsize, jnp.dtype(b_dtype).itemsize)
+    if m * dim >= DIGIT_PRODUCT_BOUND:
+        raise ValueError(
+            f"exact digit-plane dot needs {m} * dim < {DIGIT_PRODUCT_BOUND}, "
+            f"got dim {dim}")
+
+
+def digit_planes(a: jax.Array, b: jax.Array,
+                 dot: Callable[[jax.Array, jax.Array], jax.Array]
+                 ) -> List[jax.Array]:
+    """The int32 planes P_s of ``a [m, D] . b [n, D]^T``; ``dot`` contracts
+    two int8 operands into int32 (XLA's ``dot_general`` or the MXU inside a
+    Pallas kernel)."""
+    ta, tb = digits(a), digits(b)
+    planes = []
+    for s in range(len(ta) + len(tb) - 1):
+        terms = [dot(ta[i], tb[s - i]) for i in range(len(ta))
+                 if 0 <= s - i < len(tb)]
+        planes.append(sum(terms[1:], terms[0]))
+    return planes
+
+
+def _wrap64(v: int) -> int:
+    return ((v + (1 << 63)) % (1 << 64)) - (1 << 63)
+
+
+def combine_planes(planes, a: jax.Array, b: jax.Array) -> jax.Array:
+    """``sum_k a[q, k] * b[n, k]`` as int64 [m, n] from the planes of
+    ``digit_planes(a, b, ...)``; ``a``/``b`` are the operands the planes were
+    built from (zero columns included: they carry digits too)."""
+    out = planes[0].astype(jnp.int64)
+    for s in range(1, len(planes)):
+        out = out + (planes[s].astype(jnp.int64) << (8 * s))
+    ca, cb = digit_bias(a.dtype), digit_bias(b.dtype)
+    if cb:
+        out = out + _wrap64(cb) * jnp.sum(a.astype(jnp.int64), axis=-1)[:, None]
+    if ca:
+        out = out + _wrap64(ca) * jnp.sum(b.astype(jnp.int64), axis=-1)[None, :]
+    if ca and cb:
+        out = out - _wrap64(a.shape[-1] * ca * cb)
+    return out
+
+
+_int8_dot = partial(jax.lax.dot_general,
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.int32)
+
+
+def exact_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Exact ``a [m, D] . b [n, D]^T`` as int64 [m, n] for integer operands
+    of any width, with XLA int8 dots only: bit-identical to
+    ``einsum("qd,nd->qn", a.astype(int64), b.astype(int64))`` on every
+    platform, including a TPU that has no s64 ``dot``."""
+    check_digit_bound(a.dtype, b.dtype, a.shape[-1])
+    return combine_planes(digit_planes(a, b, _int8_dot), a, b)

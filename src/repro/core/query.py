@@ -116,7 +116,7 @@ def plan_query(live_count: int, k: int, ef: int, *,
          and ``dim <= 8192`` → coarse: the int8 scan streams 1/4 the
          bytes of the exact scan, so bytes beat exact once the re-rank
          pool is under 3/4 of the corpus (the break-even of
-         live*dim*1 + ef*dim*4 vs live*dim*4); the dim cap is the qcoarse
+         live*dim*1 + ef*dim*4 vs live*dim*4); the dim cap is the qgemm
          kernel's int32 exactness bound;
       6. otherwise → HNSW — including under churn. Deletes no longer
          demote the graph to exact scan: entry-point repair keeps every

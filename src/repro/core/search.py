@@ -1,9 +1,10 @@
 """Exact deterministic k-NN over the fixed-point arena.
 
-The throughput-oriented counterpart of hnsw.py (DESIGN.md §2): scoring is a
-blocked integer matmul (delegated to the Pallas qgemm kernel when enabled,
-pure jnp otherwise) and selection is a (score, id) lexicographic top-k, so
-results — including tie order — are bit-identical everywhere.
+The throughput-oriented counterpart of hnsw.py (DESIGN.md §2): scoring is an
+exact integer matmul built from int8 digit planes (``limbs.exact_dot`` in
+XLA, or the Pallas qgemm kernel when enabled) and selection is a (score, id)
+lexicographic top-k, so results — including tie order — are bit-identical
+everywhere.
 
 Scores are *wide* (unshifted Q(2f)) integers: exact, monotone in the true
 metric, never rounded before ranking.
@@ -16,9 +17,10 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import limbs
 from repro.core.state import MemoryState
 
-INF = jnp.int64(1) << 62
+INF = 1 << 62  # a Python int: importing this module creates no device array
 
 METRIC_L2 = "l2"
 METRIC_DOT = "dot"
@@ -32,11 +34,7 @@ def score_block(queries_raw: jax.Array, db_raw: jax.Array, metric: str = METRIC_
         from repro.kernels.qgemm import ops as qgemm_ops
         wide_dot = qgemm_ops.qgemm(queries_raw, db_raw)
     else:
-        wide_dot = jnp.einsum(
-            "qd,nd->qn",
-            queries_raw.astype(jnp.int64),
-            db_raw.astype(jnp.int64),
-        )
+        wide_dot = limbs.exact_dot(queries_raw, db_raw)
     if metric == METRIC_DOT:
         return -wide_dot
     if metric == METRIC_L2:
@@ -127,9 +125,9 @@ def coarse_search(state: MemoryState, table, queries_raw: jax.Array, k: int,
     Two stages (DESIGN.md §10):
 
     1. *Coarse scan*: approximate integer scores over the int8 code table
-       (``kernels/qcoarse`` when ``use_kernel``, its jnp oracle otherwise —
-       bit-identical either way), candidates = the ``ef_coarse`` best by
-       (approx score, slot).
+       (the qgemm kernel when ``use_kernel``, ``limbs.exact_dot``
+       otherwise — bit-identical either way), candidates = the
+       ``ef_coarse`` best by (approx score, slot).
     2. *Re-rank*: the survivors re-scored with the exact wide Q16.16
        ``score_block`` arithmetic and combined by ``merge_candidates`` —
        the same (score, id) tie-break every other read path uses.
@@ -145,7 +143,6 @@ def coarse_search(state: MemoryState, table, queries_raw: jax.Array, k: int,
     are (-1, INF), exactly like ``exact_search``.
     """
     from repro.core import codes as codes_lib    # lazy: codes is leaf-level
-    from repro.kernels.qcoarse import ops as qcoarse_ops
 
     n = state.vectors.shape[0]
     ef = min(ef_coarse, n)
@@ -156,7 +153,11 @@ def coarse_search(state: MemoryState, table, queries_raw: jax.Array, k: int,
             f"yield {k} results")
 
     w = codes_lib.query_weights(queries_raw, table, metric)
-    s = qcoarse_ops.qcoarse(w, table.codes, use_pallas=use_kernel)
+    if use_kernel:
+        from repro.kernels.qgemm import ops as qgemm_ops
+        s = qgemm_ops.qgemm(w, table.codes)
+    else:
+        s = limbs.exact_dot(w, table.codes)
     if metric == METRIC_L2:
         approx = table.norms[None, :] - 2 * s
     else:

@@ -1,19 +1,35 @@
 """Pallas TPU kernels for the substrate's compute hot-spots.
 
-Each kernel ships three files (kernel.py: pl.pallas_call + BlockSpec VMEM
-tiling; ops.py: jit'd public wrapper with padding/fallbacks; ref.py: pure-jnp
-oracle) and is validated BITWISE against its oracle across shape sweeps —
-integer kernels admit no tolerance.
+Each kernel ships a kernel.py (pl.pallas_call + BlockSpec VMEM tiling), an
+ops.py (jit'd public wrapper: padding, bounds, int64 combine) and a ref.py
+(the pure-jnp test oracle), and is validated BITWISE against its oracle
+across shape sweeps — integer kernels admit no tolerance.
 
-  qgemm     — exact fixed-point scoring matmul; int64 accumulation realized
-              as three int32 limb planes (TPU has no native int64)
-  qcoarse   — int8 coarse-scan scoring for the compressed tier: int32 query
-              weights decomposed into four 8-bit limb planes against int8
-              codes (1/4 the bytes streamed of the exact scan)
+  qgemm     — exact integer scoring matmul: int8 digit planes on the MXU,
+              accumulated in int32 and combined in int64 outside the kernel
+              (a TPU has no native int64 and no int32 matmul). It serves
+              the exact scan (int32 rows) and the compressed tier's coarse
+              scan (int32 query weights x int8 codes, 1/4 the bytes
+              streamed)
   qtopk     — deterministic k-smallest with tie keys over dual-plane scores
   qboundary — fused float→Q-encode→integer-L2-normalize (the paper's §5.3
-              determinism boundary, the hottest serving entry point)
+              determinism boundary); on no serving path, kept with its
+              interpret-mode tests
 
-Kernels run in interpret mode on the CPU container (exact semantics); on TPU
-the same BlockSpecs drive Mosaic compilation.
+``on_platform`` runs the serving kernels compiled by Mosaic wherever the
+computation is lowered for a TPU and in interpret mode elsewhere (exact
+semantics on the CPU), so no caller chooses a mode.
 """
+import functools
+
+import jax
+
+
+def on_platform(kernel_call, *args):
+    """``kernel_call(*args, interpret=...)``: compiled on a TPU, interpreted
+    on any other platform. The choice follows the platform the computation
+    is lowered for — where its arrays live — not a flag or a default."""
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(kernel_call, interpret=False),
+        default=functools.partial(kernel_call, interpret=True))

@@ -21,9 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.core import compat
-
-_CompilerParams = compat.pallas_tpu_compiler_params()
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _qboundary_kernel(x_ref, out_ref, *, one: int, min_raw: int, max_raw: int,
@@ -75,7 +73,7 @@ def qboundary_pallas(x: jax.Array, *, one: int, min_raw: int, max_raw: int,
         in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)

@@ -1,1 +1,1 @@
-from repro.kernels.qgemm.ops import qgemm, qgemm_planes  # noqa: F401
+from repro.kernels.qgemm.ops import qgemm  # noqa: F401

@@ -1,28 +1,28 @@
-"""Pallas TPU kernel: exact fixed-point scoring matmul (paper §5.1, hot spot).
+"""Pallas TPU kernel: exact integer scoring matmul (paper §5.1, hot spot).
 
-The paper's dot products accumulate in i64. TPUs have no native int64, so the
-TPU-native adaptation (DESIGN.md §2) decomposes each Q16.16 raw value into
-8-bit limbs
+The paper's dot products accumulate in i64. A TPU has no native int64, and
+its matrix unit multiplies int8 (or bf16), not int32. So each operand tile is
+split in VMEM into signed int8 digits (``core/limbs.py``: ``x = sum_i t_i
+256^i + bias``), every digit pair goes through the MXU as an int8 x int8 ->
+int32 matmul, and the products of equal weight 256^s are summed into one
+int32 plane P_s. The kernel writes the planes; ``ops.py`` combines them in
+int64 outside the kernel, together with the bias terms, exactly as
+``limbs.exact_dot`` does in XLA — one decomposition, two backends.
 
-    raw = h * 2^8 + l,   h = raw >> 8 (signed),  l = raw & 0xFF (unsigned)
+Exactness: every plane is a sum of int8 products, each at most 2^14 in
+magnitude, with at most min(digits) products per contracted element, so it
+fits int32 while ``min(digits) * D < 2^17`` (``limbs.check_digit_bound``;
+D <= 8192 for two int32 operands). The same bound holds for every partial
+sum across the K grid axis.
 
-and computes three int32 partial-sum planes
+One kernel serves both scans: int32 x int32 Q16.16 rows (the exact scan,
+4x4 digits, 7 planes) and int32 query weights x int8 codes (the coarse
+scan, 4x1 digits, 4 planes — the database operand streams as int8).
 
-    S_hh = Σ h·h',   S_hl = Σ (h·l' + l·h'),   S_ll = Σ l·l'
-
-whose exact int64 combination is  (S_hh << 16) + (S_hl << 8) + S_ll.
-
-Range analysis (why int32 accumulation is exact): boundary-normalized vectors
-satisfy |raw| ≤ 2^16, so |h| ≤ 2^8, l < 2^8, giving
-    |S_hh| ≤ 2^16·D,  |S_hl| ≤ 2^17·D,  |S_ll| < 2^16·D,
-all < 2^31 for D ≤ 2^13 = 8192 — checked by ops.py. The combination step runs
-outside the kernel where XLA's int64 emulation is available.
-
-Tiling: grid (nq/BQ, nn/BN, nd/BK); Q and DB tiles live in VMEM; the output
-tile [BQ, BN, 3] accumulates across the BK grid axis (revisited, 'arbitrary'
-semantics). All matmuls are lax.dot_general with int32 preferred type — on
-TPU these map to MXU/VPU integer paths; in interpret mode they are exact
-NumPy-level ops, so CPU validation is bit-exact against ref.py.
+Tiling: grid (nq/BQ, nn/BN, D/BK). Planes are plane-major, [P, nq, nn], so
+an output tile [P, BQ, BN] keeps BN on the lanes (a trailing plane axis of
+3 or 4 would pad to 128 lanes in VMEM). The tile accumulates across the BK
+grid axis ('arbitrary' semantics).
 """
 from __future__ import annotations
 
@@ -31,73 +31,64 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.core import compat
+from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = compat.pallas_tpu_compiler_params()
+from repro.core import limbs
+
+_mxu_dot = functools.partial(
+    jax.lax.dot_general,
+    dimension_numbers=(((1,), (1,)), ((), ())),  # contract BK, no batch
+    preferred_element_type=jnp.int32,
+)
 
 
-def _qgemm_kernel(q_ref, d_ref, out_ref):
-    """One (BQ, BN) output tile, accumulated across the K grid dimension."""
-    k = pl.program_id(2)
+def n_planes(a_dtype, b_dtype) -> int:
+    return jnp.dtype(a_dtype).itemsize + jnp.dtype(b_dtype).itemsize - 1
 
-    q = q_ref[...]  # [BQ, BK] int32
-    d = d_ref[...]  # [BN, BK] int32
 
-    qh = q >> 8
-    ql = q & 0xFF
-    dh = d >> 8
-    dl = d & 0xFF
-
-    dot = functools.partial(
-        jax.lax.dot_general,
-        dimension_numbers=(((1,), (1,)), ((), ())),  # contract BK, no batch
-        preferred_element_type=jnp.int32,
-    )
-    s_hh = dot(qh, dh)
-    s_hl = dot(qh, dl) + dot(ql, dh)
-    s_ll = dot(ql, dl)
-
-    planes = jnp.stack([s_hh, s_hl, s_ll], axis=-1)  # [BQ, BN, 3]
-
-    @pl.when(k == 0)
+def _digit_matmul_kernel(a_ref, b_ref, out_ref):
+    """One (BQ, BN) tile of every plane, accumulated across the K axis."""
+    @pl.when(pl.program_id(2) == 0)
     def _init():
-        out_ref[...] = planes
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(k != 0)
-    def _accum():
-        out_ref[...] += planes
+    planes = limbs.digit_planes(a_ref[...], b_ref[...], _mxu_dot)
+    for s, plane in enumerate(planes):
+        out_ref[s] += plane
 
 
-def qgemm_planes_pallas(
-    queries: jax.Array,   # [nq, d] int32 raw fixed-point
-    database: jax.Array,  # [nn, d] int32 raw fixed-point
+def digit_planes_pallas(
+    a: jax.Array,  # [nq, D] integer, at most 4 bytes
+    b: jax.Array,  # [nn, D] integer, at most 4 bytes
     *,
-    block_q: int = 128,
-    block_n: int = 128,
-    block_k: int = 512,
-    interpret: bool = True,
+    block_q: int,
+    block_n: int,
+    block_k: int,
+    interpret: bool,
 ) -> jax.Array:
-    """Returns the three int32 partial planes [nq, nn, 3].
+    """The int32 digit planes [P, nq, nn] of ``a . b^T``.
 
-    Shapes must be multiples of the block sizes (ops.py pads).
+    Shapes must be multiples of the block sizes (ops.py pads). The kernel is
+    a 32-bit program, so it is traced with x64 off: under the package's x64
+    mode its grid indices would otherwise be int64, which Mosaic refuses.
     """
-    nq, d = queries.shape
-    nn, d2 = database.shape
+    nq, d = a.shape
+    nn, d2 = b.shape
     assert d == d2, (d, d2)
     assert nq % block_q == 0 and nn % block_n == 0 and d % block_k == 0
-
-    grid = (nq // block_q, nn // block_n, d // block_k)
-    return pl.pallas_call(
-        _qgemm_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, block_k), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_n, block_k), lambda i, j, k: (j, k)),
-        ],
-        out_specs=pl.BlockSpec((block_q, block_n, 3), lambda i, j, k: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nq, nn, 3), jnp.int32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(queries, database)
+    p = n_planes(a.dtype, b.dtype)
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            _digit_matmul_kernel,
+            grid=(nq // block_q, nn // block_n, d // block_k),
+            in_specs=[
+                pl.BlockSpec((block_q, block_k), lambda i, j, k: (i, k)),
+                pl.BlockSpec((block_n, block_k), lambda i, j, k: (j, k)),
+            ],
+            out_specs=pl.BlockSpec((p, block_q, block_n),
+                                   lambda i, j, k: (0, i, j)),
+            out_shape=jax.ShapeDtypeStruct((p, nq, nn), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(a, b)

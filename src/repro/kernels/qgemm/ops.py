@@ -1,64 +1,55 @@
 """jit'd public wrapper for the qgemm kernel: padding, range checks, combine."""
 from __future__ import annotations
 
-from functools import partial
+import functools
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import limbs
+from repro.kernels import on_platform
 from repro.kernels.qgemm import kernel as _kernel
 
-# |raw| ≤ RAW_BOUND keeps all three int32 planes overflow-free up to MAX_DIM.
-RAW_BOUND = 1 << 16
+# the largest dim any caller scores (the planner's coarse-route cap agrees)
 MAX_DIM = 1 << 13
 
 
-def _pad_to(x: jax.Array, m0: int, m1: int) -> jax.Array:
-    p0 = (-x.shape[0]) % m0
-    p1 = (-x.shape[1]) % m1
-    if p0 or p1:
-        x = jnp.pad(x, ((0, p0), (0, p1)))
-    return x
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def _pick_blocks(nq: int, nn: int, d: int):
-    bq = min(128, max(8, nq))
-    bn = 128 if nn >= 128 else max(8, nn)
-    bk = 512 if d >= 512 else max(128, d) if d >= 128 else d
-    return bq, bn, bk
+    """(BQ, BN, BK, padded D). BQ and BN are multiples of 32 (the int8
+    sublane tile), D pads to the 128-lane width, and BK is the largest
+    multiple of 128 up to 1024 that divides the padded D (768 stays one
+    step, 8192 takes eight)."""
+    bq = min(128, _round_up(nq, 32))
+    bn = min(256, _round_up(nn, 32))
+    dp = _round_up(d, 128)
+    bk = max(b for b in range(128, min(dp, 1024) + 1, 128) if dp % b == 0)
+    return bq, bn, bk, dp
 
 
-@partial(jax.jit, static_argnames=("interpret", "use_pallas"))
-def qgemm_planes(queries: jax.Array, database: jax.Array, *,
-                 interpret: bool = True, use_pallas: bool = True) -> jax.Array:
-    """Three int32 limb planes [nq, nn, 3] for raw fixed-point inputs."""
-    if queries.shape[-1] > MAX_DIM:
-        raise ValueError(
-            f"qgemm exactness bound needs dim ≤ {MAX_DIM}, got {queries.shape[-1]}"
-        )
-    nq, d = queries.shape
-    nn = database.shape[0]
-    if not use_pallas:
-        from repro.kernels.qgemm import ref
-        return ref.qgemm_planes_ref(queries, database)
-    bq, bn, bk = _pick_blocks(nq, nn, d)
-    qp = _pad_to(queries.astype(jnp.int32), bq, bk)
-    dp = _pad_to(database.astype(jnp.int32), bn, bk)
-    planes = _kernel.qgemm_planes_pallas(
-        qp, dp, block_q=bq, block_n=bn, block_k=bk, interpret=interpret
-    )
-    return planes[:nq, :nn]
-
-
-@partial(jax.jit, static_argnames=("interpret", "use_pallas"))
-def qgemm(queries: jax.Array, database: jax.Array, *,
-          interpret: bool = True, use_pallas: bool = True) -> jax.Array:
-    """Exact wide int64 dot scores [nq, nn] — kernel planes + int64 combine.
-
-    Bit-identical to ref.qgemm_ref for boundary-normalized inputs
-    (|raw| ≤ 2^16, dim ≤ 8192).
-    """
-    planes = qgemm_planes(
-        queries, database, interpret=interpret, use_pallas=use_pallas
-    ).astype(jnp.int64)
-    return (planes[..., 0] << 16) + (planes[..., 1] << 8) + planes[..., 2]
+@jax.jit
+def qgemm(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Exact ``a [nq, D] . b [nn, D]^T`` as int64 [nq, nn] from the kernel's
+    digit planes — bit-identical to ``limbs.exact_dot`` and to the int64
+    einsum oracle (``ref.qgemm_ref``) on every input of at most 4 bytes."""
+    nq, d = a.shape
+    nn = b.shape[0]
+    if d > MAX_DIM:
+        raise ValueError(f"qgemm supports dim <= {MAX_DIM}, got {d}")
+    if max(a.dtype.itemsize, b.dtype.itemsize) > 4:
+        raise ValueError(f"qgemm takes operands of at most 4 bytes, got "
+                         f"{a.dtype} and {b.dtype}")
+    bq, bn, bk, dp = _pick_blocks(nq, nn, d)
+    limbs.check_digit_bound(a.dtype, b.dtype, dp)
+    ap = jnp.pad(a, ((0, _round_up(nq, bq) - nq), (0, dp - d)))
+    bp = jnp.pad(b, ((0, _round_up(nn, bn) - nn), (0, dp - d)))
+    planes = on_platform(
+        functools.partial(_kernel.digit_planes_pallas, block_q=bq,
+                          block_n=bn, block_k=bk), ap, bp)
+    # zero-padded columns carry digits too: combine over the padded width
+    return limbs.combine_planes(
+        [planes[s, :nq, :nn] for s in range(planes.shape[0])],
+        ap[:nq], bp[:nn])

@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the qgemm kernel: direct i64 accumulation (paper §5.1)."""
+"""Test oracle for the qgemm kernel: direct i64 accumulation (paper §5.1)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -9,19 +9,3 @@ def qgemm_ref(queries: jnp.ndarray, database: jnp.ndarray) -> jnp.ndarray:
     return jnp.einsum(
         "qd,nd->qn", queries.astype(jnp.int64), database.astype(jnp.int64)
     )
-
-
-def qgemm_planes_ref(queries: jnp.ndarray, database: jnp.ndarray) -> jnp.ndarray:
-    """The three-limb partial planes, computed without Pallas (for tile tests)."""
-    qh, ql = queries >> 8, queries & 0xFF
-    dh, dl = database >> 8, database & 0xFF
-    s_hh = jnp.einsum("qd,nd->qn", qh.astype(jnp.int32), dh.astype(jnp.int32))
-    s_hl = jnp.einsum("qd,nd->qn", qh.astype(jnp.int32), dl.astype(jnp.int32)) + \
-           jnp.einsum("qd,nd->qn", ql.astype(jnp.int32), dh.astype(jnp.int32))
-    s_ll = jnp.einsum("qd,nd->qn", ql.astype(jnp.int32), dl.astype(jnp.int32))
-    return jnp.stack([s_hh, s_hl, s_ll], axis=-1)
-
-
-def combine_planes_ref(planes: jnp.ndarray) -> jnp.ndarray:
-    p = planes.astype(jnp.int64)
-    return (p[..., 0] << 16) + (p[..., 1] << 8) + p[..., 2]

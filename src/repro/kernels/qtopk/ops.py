@@ -1,12 +1,13 @@
 """jit'd public wrapper for qtopk: plane split, padding, final candidate merge."""
 from __future__ import annotations
 
-from functools import partial
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_platform
 from repro.kernels.qtopk import kernel as _kernel
 
 # plain int, not a jnp scalar: a module-level jnp constant would become a
@@ -19,7 +20,7 @@ def split_planes(scores: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """int64 scores → (hi int32, sign-biased lo int32); lex order preserved."""
     s = scores.astype(jnp.int64)
     hi = (s >> 32).astype(jnp.int32)
-    lo_u = (s & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32) ^ jnp.uint32(_BIAS)
+    lo_u = (s & 0xFFFFFFFF).astype(jnp.uint32) ^ jnp.uint32(_BIAS)
     return hi, lo_u.astype(jnp.int32)
 
 
@@ -29,9 +30,12 @@ def combine_planes(hi: jax.Array, lo: jax.Array) -> jax.Array:
     return (hi.astype(jnp.int64) << 32) | lo_u
 
 
-@partial(jax.jit, static_argnames=("k", "interpret", "use_pallas"))
-def qtopk(scores: jax.Array, keys: jax.Array, k: int, *,
-          interpret: bool = True, use_pallas: bool = True
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def qtopk(scores: jax.Array, keys: jax.Array, k: int
           ) -> Tuple[jax.Array, jax.Array]:
     """Deterministic k smallest (score, key) per row.
 
@@ -39,32 +43,29 @@ def qtopk(scores: jax.Array, keys: jax.Array, k: int, *,
     Returns (scores [nq, k] int64, keys [nq, k] int32), sorted.
     Bit-identical to ref.qtopk_ref.
     """
-    if not use_pallas:
-        from repro.kernels.qtopk import ref
-        return ref.qtopk_ref(scores, keys, k)
-
     nq, n = scores.shape
-    bq = min(128, max(8, nq))
-    bn = 1024 if n >= 1024 else max(128, n) if n >= 128 else n
+    bq = min(128, _round_up(nq, 8))
+    bn = min(1024, _round_up(n, _kernel.LANES))
     hi, lo = split_planes(scores)
 
-    pq = (-nq) % bq
-    pn = (-n) % bn
-    if pq or pn:
-        hi = jnp.pad(hi, ((0, pq), (0, pn)), constant_values=2**31 - 1)
-        lo = jnp.pad(lo, ((0, pq), (0, pn)), constant_values=2**31 - 1)
-    keys_p = jnp.pad(
-        keys.astype(jnp.int32), (0, pn), constant_values=2**31 - 1
-    )[None, :]
+    pq = _round_up(nq, bq) - nq
+    pn = _round_up(n, bn) - n
+    fill = _kernel.I32_MAX
+    hi = jnp.pad(hi, ((0, pq), (0, pn)), constant_values=fill)
+    lo = jnp.pad(lo, ((0, pq), (0, pn)), constant_values=fill)
+    keys_p = jnp.pad(keys.astype(jnp.int32), (0, pn),
+                     constant_values=fill)[None, :]
 
     kk = min(k, bn)
-    c_hi, c_lo, c_key = _kernel.qtopk_pallas(
-        hi, lo, keys_p, kk, block_q=bq, block_n=bn, interpret=interpret
-    )
-    # final merge over n_blocks*k candidates (small): exact int64 sort
-    cand_scores = combine_planes(c_hi, c_lo)[:nq]
-    cand_keys = c_key[:nq]
-    s, i = jax.lax.sort(
-        (cand_scores, cand_keys.astype(jnp.int32)), num_keys=2, dimension=1
-    )
+    cands = on_platform(
+        functools.partial(_kernel.qtopk_pallas, k=kk, block_q=bq,
+                          block_n=bn), hi, lo, keys_p)
+    # drop each block's lane padding past its kk candidates
+    n_blocks = hi.shape[1] // bn
+    c_hi, c_lo, c_key = (
+        c[:nq].reshape(nq, n_blocks, -1)[:, :, :kk].reshape(nq, -1)
+        for c in cands)
+    # final merge over the per-block candidates (small): exact int64 sort
+    s, i = jax.lax.sort((combine_planes(c_hi, c_lo), c_key), num_keys=2,
+                        dimension=1)
     return s[:, :k], i[:, :k]

@@ -25,7 +25,6 @@ from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell
 from repro.models.config import SHAPES
 from repro.roofline import analysis as roofline
-from repro.core import compat
 
 OUT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
@@ -44,7 +43,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: pathlib.Path,
         if cell.skip_reason:
             record.update(status="skip", reason=cell.skip_reason)
         else:
-            with compat.use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 jitted = jax.jit(cell.step_fn, donate_argnums=cell.donate,
                                  out_shardings=cell.out_shardings)
                 lowered = jitted.lower(*cell.args)
@@ -57,7 +56,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: pathlib.Path,
                 v = getattr(mem, field, None)
                 if v is not None:
                     mem_rec[field] = int(v)
-            cost = compat.cost_analysis(compiled)
+            cost = compiled.cost_analysis() or {}
             rf = roofline.analyze(compiled, chips)
             record.update(
                 status="ok",

@@ -7,13 +7,12 @@ from __future__ import annotations
 
 import jax
 
-from repro.core import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(model: int | None = None, data: int | None = None):
@@ -26,7 +25,8 @@ def make_host_mesh(model: int | None = None, data: int | None = None):
                 model = m
                 break
     data = data or (n // model)
-    return compat.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh) -> tuple:

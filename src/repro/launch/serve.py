@@ -26,6 +26,11 @@ Topology flags (DESIGN.md §7, §8):
   --ef-coarse N        candidate-set size for the compressed coarse tier
                        (DESIGN.md §10); defaulted to cover the corpus when
                        --route coarse is forced without it
+
+A chip belongs to one process. This process's engine holds the accelerator,
+so every ``--spawn-shards`` child runs on the CPU platform
+(``JAX_PLATFORMS=cpu``, printed per child); shard answers are bit-identical
+across platforms, so the merged read is the same.
 """
 from __future__ import annotations
 
@@ -39,7 +44,7 @@ import time
 import jax
 import numpy as np
 
-import repro  # noqa: F401
+import repro
 from repro.configs import get_config, get_reduced_config
 from repro.core import hnsw
 from repro.models import transformer as tf
@@ -50,20 +55,25 @@ from repro.serve.engine import MemoryAugmentedEngine, ServeConfig
 def _spawn_shard_servers(n: int, capacity: int, dim: int, workdir: str):
     """Start n shard-server subprocesses on ephemeral ports; returns
     (procs, ["127.0.0.1:<port>", ...]) once every server printed its
-    LISTENING line (i.e. is accepting connections)."""
+    LISTENING line (i.e. is accepting connections). Each child runs on the
+    CPU platform: the parent holds the accelerator, and a child that reached
+    for it would fail or hang."""
     procs, hosts = [], []
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     for s in range(n):
         proc = subprocess.Popen(
             [sys.executable, "-m", "repro.net.server",
              "--dir", os.path.join(workdir, f"shard_{s}"),
              "--capacity", str(capacity // n), "--dim", str(dim),
              "--port", "0"],
-            stdout=subprocess.PIPE, text=True, env=dict(os.environ))
+            stdout=subprocess.PIPE, text=True, env=env)
         line = proc.stdout.readline().strip()
         if not line.startswith("LISTENING "):
             raise RuntimeError(f"shard server {s} failed to start: {line!r}")
         hosts.append(f"127.0.0.1:{int(line.split()[1])}")
         procs.append(proc)
+        print(f"shard server {s}: {hosts[-1]} on platform "
+              f"{env['JAX_PLATFORMS']}")
     return procs, hosts
 
 
@@ -109,6 +119,7 @@ def main() -> None:
                     help="schedule the deterministic HNSW re-link pass at "
                          "this dead fraction (DESIGN.md §11); 0 disables")
     args = ap.parse_args()
+    repro.use_compile_cache()
     if args.route == "coarse" and args.ef_coarse <= 0:
         # a forced coarse route needs a candidate-set size; cover the
         # whole corpus, which also makes the answer bit-equal to exact

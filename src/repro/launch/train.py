@@ -26,7 +26,6 @@ from repro.models import transformer as tf
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.runtime.coordinator import Coordinator, RunConfig
 from repro.train.step import make_train_step
-from repro.core import compat
 
 
 def main() -> None:
@@ -65,7 +64,7 @@ def main() -> None:
         opt = adamw_init(params)
         return {"params": params, "opt": opt}
 
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_shapes = jax.eval_shape(
             lambda: tf.init_params(cfg, jax.random.PRNGKey(0)))
         p_sh = shd.param_shardings(params_shapes, cfg, mesh)

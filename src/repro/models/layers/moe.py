@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from repro.models import pspec
 from repro.models.config import ModelConfig
 from repro.models.initializers import dense_init
-from repro.core import compat
 
 
 def init_moe(key, cfg: ModelConfig) -> dict:
@@ -122,7 +121,7 @@ def _moe_shardmap(params: dict, x: jax.Array, cfg: ModelConfig, mesh
     # dim explicitly. (Partial-manual psum crashes XLA CPU's
     # AllReducePromotion; fully-manual works but requires the caller's jit to
     # pass explicit out_shardings — see train/step.py.)
-    @partial(compat.shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_specs, P(dp, None, None)),
              out_specs=(P(dp, None, None), P()),
              check_vma=False)

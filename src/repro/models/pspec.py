@@ -11,11 +11,11 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
-
 
 def _mesh():
-    return compat.get_abstract_mesh()
+    """The ambient (``jax.set_mesh``) mesh, or None when there is none."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 def dp_axes(mesh) -> tuple:

@@ -44,6 +44,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+import repro
 from repro.core import hashing, machine, query as query_lib, snapshot
 from repro.core.commands import log_from_bytes, log_to_bytes
 from repro.core.contracts import DEFAULT_CONTRACT, get_contract
@@ -487,6 +488,7 @@ def main(argv=None) -> None:
     ap.add_argument("--port", type=int, default=0,
                     help="0 binds an ephemeral port (printed on stdout)")
     args = ap.parse_args(argv)
+    repro.use_compile_cache()
 
     directory = pathlib.Path(args.dir)
     genesis = None

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 from repro.models import transformer as tf
 from repro.models.config import ModelConfig
 from repro.optim.adamw import AdamWConfig, adamw_update
-from repro.core import compat
 
 
 def make_train_step(cfg: ModelConfig, optc: AdamWConfig):
@@ -41,7 +40,7 @@ def make_compressed_train_step(cfg: ModelConfig, optc: AdamWConfig, mesh,
 
     def step(params, opt_state, batch):
         @functools.partial(
-            compat.shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(), P(), P("pod")),
             out_specs=(P(), P(), P()),
             axis_names={"pod"}, check_vma=False,

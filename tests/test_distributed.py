@@ -19,8 +19,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.core import boundary, commands, distributed, hashing, machine, search
     from repro.core.state import init_state
 
-    from repro.core import compat
-    mesh = compat.make_mesh((4, 2), ("model", "data"))
+    mesh = jax.make_mesh((4, 2), ("model", "data"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     D, N, K = 16, 96, 5
     rng = np.random.default_rng(0)
     vecs = boundary.normalize_embedding(rng.normal(size=(N, D)).astype(np.float32))
@@ -41,7 +40,7 @@ _SCRIPT = textwrap.dedent("""
 
     # replay determinism across different shard counts: 2 vs 4 shards give
     # identical search answers
-    mesh2 = compat.make_mesh((2, 4), ("model", "data"))
+    mesh2 = jax.make_mesh((2, 4), ("model", "data"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     st2 = distributed.init_sharded_state(mesh2, "model", 128, D)
     st2 = distributed.distributed_replay(mesh2, "model", st2,
                                          distributed.route_commands(log, 2))

@@ -117,16 +117,15 @@ def test_qboundary_unit_norm_property():
 
 
 # --------------------------------------------------------------------------- #
-# qcoarse: the compressed tier's int8 coarse scan (DESIGN.md §10)
+# the compressed tier's coarse scan: int32 weights x int8 codes through the
+# same qgemm kernel (DESIGN.md §10)
 # --------------------------------------------------------------------------- #
 
 from repro.core import codes as codes_lib  # noqa: E402
 from repro.core import commands, machine, search  # noqa: E402
 from repro.core.state import init_state  # noqa: E402
-from repro.kernels.qcoarse import ops as qcoarse_ops  # noqa: E402
-from repro.kernels.qcoarse import ref as qcoarse_ref  # noqa: E402
 
-W = qcoarse_ops.W_BOUND
+W = codes_lib.W_BOUND
 
 
 @pytest.mark.parametrize("nq,nn,d", [
@@ -137,8 +136,8 @@ def test_qcoarse_exact_vs_oracle(nq, nn, d):
     """Odd/prime/padded shapes: the Pallas planes + combine == direct i64."""
     w = RNG.integers(-W, W + 1, size=(nq, d)).astype(np.int32)
     c = RNG.integers(-127, 128, size=(nn, d)).astype(np.int8)
-    got = qcoarse_ops.qcoarse(jnp.asarray(w), jnp.asarray(c))
-    want = qcoarse_ref.qcoarse_ref(jnp.asarray(w), jnp.asarray(c))
+    got = qgemm_ops.qgemm(jnp.asarray(w), jnp.asarray(c))
+    want = qgemm_ref.qgemm_ref(jnp.asarray(w), jnp.asarray(c))
     assert (np.asarray(got) == np.asarray(want)).all()
 
 
@@ -149,8 +148,8 @@ def test_qcoarse_extreme_values():
     w[1] = -W
     c = np.concatenate([np.full((1, d), 127, np.int8),
                         np.full((1, d), -127, np.int8)])
-    got = qcoarse_ops.qcoarse(jnp.asarray(w), jnp.asarray(c))
-    want = qcoarse_ref.qcoarse_ref(jnp.asarray(w), jnp.asarray(c))
+    got = qgemm_ops.qgemm(jnp.asarray(w), jnp.asarray(c))
+    want = qgemm_ref.qgemm_ref(jnp.asarray(w), jnp.asarray(c))
     assert (np.asarray(got) == np.asarray(want)).all()
     assert int(got[0, 0]) == d * W * 127
 
@@ -159,7 +158,7 @@ def test_qcoarse_rejects_oversized_dim():
     w = np.zeros((2, 16384), np.int32)
     c = np.zeros((2, 16384), np.int8)
     with pytest.raises(ValueError, match="dim"):
-        qcoarse_ops.qcoarse(jnp.asarray(w), jnp.asarray(c))
+        qgemm_ops.qgemm(jnp.asarray(w), jnp.asarray(c))
 
 
 @given(st.integers(1, 5), st.integers(1, 140), st.integers(8, 96))
@@ -167,8 +166,8 @@ def test_qcoarse_rejects_oversized_dim():
 def test_qcoarse_property(nq, nn, d):
     w = RNG.integers(-W, W + 1, size=(nq, d)).astype(np.int32)
     c = RNG.integers(-127, 128, size=(nn, d)).astype(np.int8)
-    got = qcoarse_ops.qcoarse(jnp.asarray(w), jnp.asarray(c))
-    want = qcoarse_ref.qcoarse_ref(jnp.asarray(w), jnp.asarray(c))
+    got = qgemm_ops.qgemm(jnp.asarray(w), jnp.asarray(c))
+    want = qgemm_ref.qgemm_ref(jnp.asarray(w), jnp.asarray(c))
     assert (np.asarray(got) == np.asarray(want)).all()
 
 
@@ -196,7 +195,7 @@ def _coarse_state(n_live, d, n_dead=0, duplicate_rows=0, seed=7):
 
 @pytest.mark.parametrize("metric", ["l2", "dot"])
 def test_coarse_search_kernel_parity(metric):
-    """use_kernel=True (Pallas qcoarse + qtopk) == jnp path, bit for bit."""
+    """use_kernel=True (Pallas qgemm + qtopk) == jnp path, bit for bit."""
     st_ = _coarse_state(37, 24)
     tbl = codes_lib.build(st_)
     q = RNG.integers(-65536, 65537, (5, 24)).astype(np.int32)

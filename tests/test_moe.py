@@ -72,13 +72,12 @@ _SMAP = textwrap.dedent("""
     from repro.optim.adamw import AdamWConfig, adamw_init
     from repro.train.step import make_train_step
 
-    from repro.core import compat
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_reduced_config('phi3_5_moe_42b_a6_6b')
     params = moe_lib.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model), jnp.float32)
     y_dense, _ = moe_lib._moe_dense(params, x, cfg)
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_smap, _ = jax.jit(lambda p, x: moe_lib.moe_ffn(p, x, cfg))(params, x)
     err = float(jnp.max(jnp.abs(y_dense - y_smap)))
     assert err == 0.0, f"shard_map EP diverged from dense: {err}"
@@ -89,7 +88,7 @@ _SMAP = textwrap.dedent("""
     batch = {'tokens': jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, cfg.vocab_size),
              'labels': jax.random.randint(jax.random.PRNGKey(3), (8, 16), 0, cfg.vocab_size)}
     step = make_train_step(cfg, AdamWConfig())
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         p_sh = shd.param_shardings(jax.eval_shape(lambda: full), cfg, mesh)
         rep = NamedSharding(mesh, P())
         o_sh = {"m": p_sh, "v": p_sh, "step": rep}

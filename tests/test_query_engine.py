@@ -240,11 +240,11 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax, jax.numpy as jnp
     import repro
-    from repro.core import (boundary, commands, compat, distributed, hnsw,
+    from repro.core import (boundary, commands, distributed, hnsw,
                             machine, query, search)
     from repro.core.state import init_state
 
-    mesh = compat.make_mesh((4, 2), ("model", "data"))
+    mesh = jax.make_mesh((4, 2), ("model", "data"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     D, N, K = 16, 56, 8
     rng = np.random.default_rng(0)
     raw = rng.normal(size=(N, D)).astype(np.float32)

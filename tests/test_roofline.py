@@ -14,8 +14,7 @@ def _compiled(fn, *args):
 
 
 def _cost(compiled):
-    from repro.core import compat
-    return compat.cost_analysis(compiled)
+    return compiled.cost_analysis() or {}
 
 
 def test_matches_cost_analysis_single_matmul():

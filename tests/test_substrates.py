@@ -170,12 +170,12 @@ _COMPRESS = textwrap.dedent("""
     import repro
     from repro.optim import compress
 
-    from repro.core import compat
-    mesh = compat.make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     grads = {"w": jnp.asarray(np.random.default_rng(0).normal(
         size=(4, 16, 16)).astype(np.float32) * 1e-3)}
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("pod"),),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"),),
                        out_specs=P(), check_vma=False)
     def reduce_q(g):
         g = jax.tree.map(lambda a: a[0], g)
@@ -218,7 +218,7 @@ _CROSS_SUBSTRATE = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np, jax, jax.numpy as jnp
     import repro
-    from repro.core import (boundary, commands, compat, distributed, hashing,
+    from repro.core import (boundary, commands, distributed, hashing,
                             hnsw, machine, search)
     from repro.core.state import init_state
 
@@ -241,7 +241,7 @@ _CROSS_SUBSTRATE = textwrap.dedent("""
 
     # substrate 3: sharded memory, routed log bulk-applied per shard
     def sharded_ids(n_shards, mesh_shape):
-        mesh = compat.make_mesh(mesh_shape, ("model", "data"))
+        mesh = jax.make_mesh(mesh_shape, ("model", "data"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         st = distributed.init_sharded_state(mesh, "model", 128 // n_shards, D)
         st = distributed.distributed_bulk_apply(
             mesh, "model", st, distributed.route_commands(log, n_shards))
