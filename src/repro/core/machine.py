@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import hnsw
 from repro.core.commands import (DELETE, INSERT, LINK, NOP, NUM_OPCODES,
                                  SET_META, UNLINK, CommandLog)
@@ -242,11 +243,10 @@ def _pow2(n: int) -> int:
     return 1 << (n - 1).bit_length() if n > 1 else 1
 
 
-@partial(jax.jit, static_argnames=("ef_construction",))
-def _apply_insert_segment(state: MemoryState, log: CommandLog,
-                          n_real: jax.Array, *, ef_construction: int
-                          ) -> MemoryState:
-    """Clean INSERT run: all ids fresh and distinct (host-verified).
+def _claim_slots(state: MemoryState, log: CommandLog, n_real: jax.Array
+                 ) -> Tuple[MemoryState, jax.Array]:
+    """Write a clean INSERT run's rows into free slots; returns the state
+    and each command's slot (``capacity`` where rejected or padding).
 
     Slot allocation is one prefix scan: command i takes the i-th lowest free
     slot; commands past the free-slot supply are rejected, exactly like the
@@ -286,6 +286,18 @@ def _apply_insert_segment(state: MemoryState, log: CommandLog,
         cursor=cursor, meta=meta, links=links,
         version=state.version + n_real,
     )
+    return state, slots
+
+
+@partial(jax.jit, static_argnames=("ef_construction",))
+def _apply_insert_segment(state: MemoryState, log: CommandLog,
+                          n_real: jax.Array, *, ef_construction: int
+                          ) -> MemoryState:
+    """Clean INSERT run: all ids fresh and distinct (host-verified). The
+    rows go in by ``_claim_slots``, then the graph is built over them."""
+    cap = state.capacity
+    with jax.named_scope("slot_scatter"):
+        state, slots = _claim_slots(state, log, n_real)
 
     # graph construction stays ordered over the fresh rows only; rejected and
     # padded entries carry slot == cap and skip at runtime. The scan carries
@@ -303,7 +315,8 @@ def _apply_insert_segment(state: MemoryState, log: CommandLog,
         return jax.lax.cond(slot < cap, insert, lambda c: c, carry), None
 
     carry0 = (state.hnsw_neighbors, state.hnsw_levels, state.hnsw_entry)
-    (nbrs, lvls, ent), _ = jax.lax.scan(body, carry0, slots)
+    with jax.named_scope("hnsw_insert"):
+        (nbrs, lvls, ent), _ = jax.lax.scan(body, carry0, slots)
     return dataclasses.replace(
         state, hnsw_neighbors=nbrs, hnsw_levels=lvls, hnsw_entry=ent)
 
@@ -388,9 +401,9 @@ class _HostAllocator:
     are therefore hazards and take the sequential path."""
 
     def __init__(self, state: MemoryState):
-        ids_h = np.asarray(state.ids)
-        valid_h = np.asarray(state.valid)
-        levels_h = np.asarray(state.hnsw_levels)
+        ids_h = obs.to_host(state.ids)
+        valid_h = obs.to_host(state.valid)
+        levels_h = obs.to_host(state.hnsw_levels)
         self.id2slot = {int(i): int(s)
                         for s, i in enumerate(ids_h) if valid_h[s]}
         self.free = [int(s) for s in np.nonzero(~valid_h)[0]]  # already sorted
@@ -499,12 +512,20 @@ def bulk_apply(state: MemoryState, log: CommandLog,
     if n == 0:
         return state
 
-    opcode = np.asarray(log.opcode)
-    arg0 = np.asarray(log.arg0)
-    arg1 = np.asarray(log.arg1)
-    arg2 = np.asarray(log.arg2)
+    # plan on the host: fetch the log, mirror the allocator, segment
+    with obs.span("bulk_apply.plan"):
+        opcode, arg0, arg1, arg2 = (obs.to_host(a) for a in (
+            log.opcode, log.arg0, log.arg1, log.arg2))
+        segments = _segment_log(opcode, arg0, _HostAllocator(state))
+    with obs.span("bulk_apply.dispatch"):
+        return _dispatch(state, log, segments, arg0, arg1, arg2,
+                         ef_construction)
 
-    for kind, a, b, aux in _segment_log(opcode, arg0, _HostAllocator(state)):
+
+def _dispatch(state: MemoryState, log: CommandLog, segments, arg0, arg1,
+              arg2, ef_construction: int) -> MemoryState:
+    """Run each segment's batched program, in log order."""
+    for kind, a, b, aux in segments:
         m = b - a
         n_real = jnp.int32(m)
         if kind == "nop":

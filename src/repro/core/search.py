@@ -29,19 +29,21 @@ METRIC_DOT = "dot"
 def score_block(queries_raw: jax.Array, db_raw: jax.Array, metric: str = METRIC_L2,
                 use_kernel: bool = False) -> jax.Array:
     """Wide integer scores [nq, nd]; lower = better for both metrics
-    (dot scores are negated so selection logic is uniform)."""
-    if use_kernel:
-        from repro.kernels.qgemm import ops as qgemm_ops
-        wide_dot = qgemm_ops.qgemm(queries_raw, db_raw)
-    else:
-        wide_dot = limbs.exact_dot(queries_raw, db_raw)
-    if metric == METRIC_DOT:
-        return -wide_dot
-    if metric == METRIC_L2:
+    (dot scores are negated so selection logic is uniform). Its ops carry
+    the ``scan`` scope in the compiled program."""
+    if metric not in (METRIC_DOT, METRIC_L2):
+        raise ValueError(f"unknown metric {metric!r}")
+    with jax.named_scope("scan"):
+        if use_kernel:
+            from repro.kernels.qgemm import ops as qgemm_ops
+            wide_dot = qgemm_ops.qgemm(queries_raw, db_raw)
+        else:
+            wide_dot = limbs.exact_dot(queries_raw, db_raw)
+        if metric == METRIC_DOT:
+            return -wide_dot
         qq = jnp.sum(queries_raw.astype(jnp.int64) ** 2, axis=-1)  # [nq]
         nn = jnp.sum(db_raw.astype(jnp.int64) ** 2, axis=-1)  # [nd]
         return qq[:, None] - 2 * wide_dot + nn[None, :]
-    raise ValueError(f"unknown metric {metric!r}")
 
 
 def topk_by_score(scores: jax.Array, ids: jax.Array, k: int
@@ -49,11 +51,14 @@ def topk_by_score(scores: jax.Array, ids: jax.Array, k: int
     """Deterministic top-k smallest scores with (score, id) tie-break.
 
     scores [nq, n] int64, ids [n] int64 → (scores [nq,k], ids [nq,k]).
+    Its ops carry the ``select`` scope.
     """
     nq, n = scores.shape
-    ids_b = jnp.broadcast_to(ids[None, :], (nq, n))
-    s_sorted, i_sorted = jax.lax.sort((scores, ids_b), num_keys=2, dimension=1)
-    return s_sorted[:, :k], i_sorted[:, :k]
+    with jax.named_scope("select"):
+        ids_b = jnp.broadcast_to(ids[None, :], (nq, n))
+        s_sorted, i_sorted = jax.lax.sort((scores, ids_b), num_keys=2,
+                                          dimension=1)
+        return s_sorted[:, :k], i_sorted[:, :k]
 
 
 def _topk_by_score_kernel(scores: jax.Array, ids: jax.Array, k: int
@@ -65,16 +70,17 @@ def _topk_by_score_kernel(scores: jax.Array, ids: jax.Array, k: int
     the unique real ids, so (score, rank) order equals (score, id) order;
     masked rows all share id 2^62 and score INF, and every INF result is
     normalized to (-1, INF) downstream, so their internal tie order is
-    unobservable.
+    unobservable. Its ops carry the ``select`` scope.
     """
     from repro.kernels.qtopk import ops as qtopk_ops
     n = ids.shape[0]
-    order = jnp.argsort(ids)  # stable integer sort
-    ranks = jnp.zeros((n,), jnp.int32).at[order].set(
-        jnp.arange(n, dtype=jnp.int32))
-    sorted_ids = ids[order]
-    s, r = qtopk_ops.qtopk(scores, ranks, k)
-    return s, sorted_ids[jnp.clip(r, 0, n - 1)]
+    with jax.named_scope("select"):
+        order = jnp.argsort(ids)  # stable integer sort
+        ranks = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        sorted_ids = ids[order]
+        s, r = qtopk_ops.qtopk(scores, ranks, k)
+        return s, sorted_ids[jnp.clip(r, 0, n - 1)]
 
 
 @partial(jax.jit, static_argnames=("k", "metric", "use_kernel"))
@@ -89,9 +95,12 @@ def exact_search(state: MemoryState, queries_raw: jax.Array, k: int,
     (tests/test_query_engine.py::test_kernel_parity).
     """
     scores = score_block(queries_raw, state.vectors, metric, use_kernel)
-    scores = jnp.where(state.valid[None, :], scores, INF)
-    # tombstoned ids are -1; give them +inf-ish id so they sort last among ties
-    ids = jnp.where(state.valid, state.ids, jnp.int64(1) << 62)
+    with jax.named_scope("scan"):
+        scores = jnp.where(state.valid[None, :], scores, INF)
+    with jax.named_scope("select"):
+        # tombstoned ids are -1; give them +inf-ish id so they sort last
+        # among ties
+        ids = jnp.where(state.valid, state.ids, jnp.int64(1) << 62)
     if use_kernel:
         s, i = _topk_by_score_kernel(scores, ids, k)
     else:

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import distributed, hashing, hnsw, machine, search, snapshot, wal
 from repro.core.commands import CommandLog
 from repro.core.durability import _RESTORE_ERRORS, DurableStore
@@ -386,7 +387,7 @@ def live_count(state: MemoryState) -> int:
     """Total live rows of a MemoryState in either layout (flat scalar
     ``count`` or sharded ``[n_shards]`` counts) — the planner fact the
     serve engine feeds ``query.plan_query`` regardless of mode."""
-    return int(np.asarray(state.count).sum())
+    return int(obs.to_host(state.count).sum())
 
 
 def bulk_apply_sharded(state: MemoryState, log: CommandLog, n_shards: int,

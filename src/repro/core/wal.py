@@ -54,6 +54,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import hashing
 from repro.core.commands import (DELETE, INSERT, LINK, NOP, SET_META, UNLINK,
                                  CommandLog)
@@ -372,7 +373,8 @@ class WriteAheadLog:
         with open(tmp, "wb") as f:  # fsync+rename: a crash can leave a
             f.write(hdr)            # stale .tmp (ignored on open), never a
             f.flush()               # torn header at the live name
-            os.fsync(f.fileno())
+            with obs.span("wal.fsync"):
+                os.fsync(f.fileno())
         tmp.rename(path)
         self._chain = _fnv1a(hdr[:-8])
         self._segments.append((self.t, path, 0))
@@ -380,11 +382,8 @@ class WriteAheadLog:
 
     # ------------------------------------------------------------------ #
     def _validated_fields(self, log: CommandLog) -> Tuple[np.ndarray, ...]:
-        opcode = np.asarray(log.opcode)
-        arg0 = np.asarray(log.arg0)
-        arg1 = np.asarray(log.arg1)
-        arg2 = np.asarray(log.arg2)
-        vec = np.asarray(log.vec)
+        opcode, arg0, arg1, arg2, vec = (obs.to_host(a) for a in (
+            log.opcode, log.arg0, log.arg1, log.arg2, log.vec))
         if vec.shape[1] != self._dim:
             raise ValueError(f"log dim {vec.shape[1]} != WAL dim {self._dim}")
         expected = np.dtype(jnp.dtype(self.contract.storage_dtype).name)
@@ -404,7 +403,8 @@ class WriteAheadLog:
         ``append_many`` / ``GroupCommitWriter``) amortizes that cost."""
         if len(log) == 0:
             return self.t
-        return self._append_fields(*self._validated_fields(log))
+        with obs.span("wal.append"):
+            return self._append_fields(*self._validated_fields(log))
 
     def append_many(self, logs: Sequence[CommandLog]) -> int:
         """Group commit: durably append several command logs with a single
@@ -419,18 +419,20 @@ class WriteAheadLog:
         logs = [log for log in logs if len(log)]
         if not logs:
             return self.t
-        fields = [self._validated_fields(log) for log in logs]
-        # NOP runs must not merge across log boundaries: each log's records
-        # are encoded exactly as a lone append would encode them, so the
-        # grouped segment bytes equal the ungrouped ones (the §6 audit
-        # contract tests/test_group_commit.py pins byte-for-byte)
-        breaks, acc = set(), 0
-        for f in fields[:-1]:
-            acc += len(f[0])
-            breaks.add(acc)
-        return self._append_fields(
-            *(np.concatenate([f[j] for f in fields]) for j in range(5)),
-            run_breaks=frozenset(breaks))
+        with obs.span("wal.append"):
+            fields = [self._validated_fields(log) for log in logs]
+            # NOP runs must not merge across log boundaries: each log's
+            # records are encoded exactly as a lone append would encode
+            # them, so the grouped segment bytes equal the ungrouped ones
+            # (the §6 audit contract tests/test_group_commit.py pins
+            # byte-for-byte)
+            breaks, acc = set(), 0
+            for f in fields[:-1]:
+                acc += len(f[0])
+                breaks.add(acc)
+            return self._append_fields(
+                *(np.concatenate([f[j] for f in fields]) for j in range(5)),
+                run_breaks=frozenset(breaks))
 
     def _append_fields(self, opcode, arg0, arg1, arg2, vec, *,
                        run_breaks: frozenset = frozenset()) -> int:
@@ -471,7 +473,8 @@ class WriteAheadLog:
             with open(path, "ab") as f:
                 f.write(bytes(buf))
                 f.flush()
-                os.fsync(f.fileno())
+                with obs.span("wal.fsync"):
+                    os.fsync(f.fileno())
             self._chain = chain
             self._cur_records = cnt + wrote
             self._segments[-1] = (base, path, self._cur_records)

@@ -91,4 +91,5 @@ def digit_planes_pallas(
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
+            name="qgemm",
         )(a, b)

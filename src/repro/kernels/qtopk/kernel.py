@@ -98,4 +98,5 @@ def qtopk_pallas(
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
+            name="qtopk",
         )(hi, lo, key)

@@ -11,8 +11,8 @@ Wraps the train loop with the large-scale survival kit:
     that exceed `deadline_factor` × median step time get flagged, and after
     `evict_after` consecutive flags the coordinator treats the rank as
     failed and triggers the elastic path (the standard "fail-slow = fail"
-    doctrine). On a single-host dry run, timings come from the host clock;
-    the policy logic is exercised by tests with synthetic timings.
+    doctrine). Per-rank step times come from the caller's
+    `rank_times_fn`; tests exercise the policy with synthetic timings.
 
 The loop itself is deliberately simple: all the intelligence lives in the
 substrate (deterministic data order, hashable state, divisibility-aware
@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import dataclasses
 import statistics
-import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -83,7 +82,6 @@ class Coordinator:
         self.ckpt = CheckpointManager(run.checkpoint_dir,
                                       keep=run.keep_checkpoints,
                                       async_save=False)
-        self.step_times: List[float] = []
         self.flag_counts: Dict[int, int] = {}
         self.restarts = 0
         self.events: List[dict] = []
@@ -127,10 +125,8 @@ class Coordinator:
                 if fail:
                     raise RuntimeError(f"injected failure: {fail}")
 
-                t0 = time.monotonic()
                 batch = self.batch_fn(step)
                 state, metrics = self.train_step(state, batch)
-                self.step_times.append(time.monotonic() - t0)
 
                 if rank_times_fn is not None:
                     evict = self._check_stragglers(rank_times_fn(step))

@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import boundary, commands, distributed, hnsw, machine, \
     query, shard_wal, snapshot
 from repro.core import wal as wal_lib
@@ -490,6 +491,10 @@ class MemoryAugmentedEngine:
         sequential in BOTH modes: the same documents produce the same
         command log everywhere, which is what makes the two modes
         conformance-comparable (DESIGN.md §7)."""
+        with obs.span("engine.insert"):
+            return self._insert_documents(token_batches)
+
+    def _insert_documents(self, token_batches: np.ndarray) -> List[int]:
         if len(token_batches) == 0:
             # routing pads an empty batch to one NOP per shard, which would
             # advance the sharded memory cursor while both durable paths
@@ -662,6 +667,11 @@ class MemoryAugmentedEngine:
         above (read-your-writes: a replica may lag the log, never the
         reader). Otherwise the primary serves, and either way
         ``last_plan.served_by`` records who answered (DESIGN.md §9)."""
+        with obs.span("engine.retrieve"):
+            return self._retrieve(prompt_tokens, k)
+
+    def _retrieve(self, prompt_tokens: np.ndarray, k: Optional[int]
+                  ) -> Tuple[np.ndarray, np.ndarray]:
         k = k or self.sc.retrieve_k
         # sync-on-read barrier: nothing un-durable is observable, and the
         # cursor it returns is the read-your-writes floor for replica routes
