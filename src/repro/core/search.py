@@ -50,37 +50,57 @@ def topk_by_score(scores: jax.Array, ids: jax.Array, k: int
                   ) -> Tuple[jax.Array, jax.Array]:
     """Deterministic top-k smallest scores with (score, id) tie-break.
 
-    scores [nq, n] int64, ids [n] int64 → (scores [nq,k], ids [nq,k]).
-    Its ops carry the ``select`` scope.
-    """
-    nq, n = scores.shape
-    with jax.named_scope("select"):
-        ids_b = jnp.broadcast_to(ids[None, :], (nq, n))
-        s_sorted, i_sorted = jax.lax.sort((scores, ids_b), num_keys=2,
-                                          dimension=1)
-        return s_sorted[:, :k], i_sorted[:, :k]
+    scores [nq, n] int64, ids [n] int64 and unique → (scores [nq,k],
+    ids [nq,k]). Its ops carry the ``select`` scope.
 
+    One exact select, two ways to compute it, chosen from what the code
+    observes and never from a flag:
 
-def _topk_by_score_kernel(scores: jax.Array, ids: jax.Array, k: int
-                          ) -> Tuple[jax.Array, jax.Array]:
-    """qtopk-backed top-k, bit-identical to :func:`topk_by_score`.
+    - lowered for a TPU, with ``k <= 128`` and ``n`` of at least one of
+      qtopk's blocks: ``kernels.qtopk``, one streaming pass over the
+      row's blocks that keeps a running k best;
+    - anywhere else (any other platform, a larger k, a row shorter than a
+      block): one two-key ``lax.sort`` of the whole row.
 
-    The kernel tie-breaks on int32 keys, but ids are int64. Rank each id
-    among the sorted id column instead: id → rank is strictly monotone for
-    the unique real ids, so (score, rank) order equals (score, id) order;
-    masked rows all share id 2^62 and score INF, and every INF result is
-    normalized to (-1, INF) downstream, so their internal tie order is
-    unobservable. Its ops carry the ``select`` scope.
+    Both return the same bits (tests/test_query_engine.py). The rule is
+    fixed per compiled program: the shapes are static and the platform is
+    the one the computation is lowered for (``lax.platform_dependent``).
     """
     from repro.kernels.qtopk import ops as qtopk_ops
-    n = ids.shape[0]
     with jax.named_scope("select"):
-        order = jnp.argsort(ids)  # stable integer sort
-        ranks = jnp.zeros((n,), jnp.int32).at[order].set(
-            jnp.arange(n, dtype=jnp.int32))
-        sorted_ids = ids[order]
-        s, r = qtopk_ops.qtopk(scores, ranks, k)
-        return s, sorted_ids[jnp.clip(r, 0, n - 1)]
+        if not blockwise_applies(scores.shape[1], k):
+            return _sort_topk(scores, ids, k)
+        return jax.lax.platform_dependent(
+            scores, ids, tpu=partial(qtopk_ops.qtopk, k=k),
+            default=partial(_sort_topk, k=k))
+
+
+# qtopk pays a threshold pass over every block and up to k min passes more:
+# about one a block once the running k best settle where the row's order is
+# unrelated to the query, k in every block where scores fall along the row
+# (its worst case). On a TPU v5e, 128 rows of 2^20 (PERF.md, Findings): the
+# sort takes 529 ms at any k; qtopk 13.8 ms (k = 10) to 58.5 ms (k = 1024)
+# in random order, and in falling order 29, 223, 483, 1,172 and 3,215 ms at
+# k = 10, 128, 256, 512, 1024. One row of 2^20: sort 22.1 ms, qtopk 2.0 ms
+# random, 9.0 (k = 10) and 90.7 ms (k = 128) falling. At 1,024 to 16,384
+# rows both take 0.9-4.5 ms, qtopk ahead at k = 10 in either order.
+_BLOCKWISE_MAX_K = 128
+
+
+def blockwise_applies(n: int, k: int) -> bool:
+    """Whether :func:`topk_by_score` selects blockwise on a TPU for a row
+    of ``n`` scores and ``k`` results (static shapes, plain Python)."""
+    from repro.kernels.qtopk import ops as qtopk_ops
+    return k <= _BLOCKWISE_MAX_K and n >= qtopk_ops.BLOCK_N
+
+
+def _sort_topk(scores: jax.Array, ids: jax.Array, k: int
+               ) -> Tuple[jax.Array, jax.Array]:
+    nq, n = scores.shape
+    ids_b = jnp.broadcast_to(ids[None, :], (nq, n))
+    s_sorted, i_sorted = jax.lax.sort((scores, ids_b), num_keys=2,
+                                      dimension=1)
+    return s_sorted[:, :k], i_sorted[:, :k]
 
 
 @partial(jax.jit, static_argnames=("k", "metric", "use_kernel"))
@@ -90,21 +110,21 @@ def exact_search(state: MemoryState, queries_raw: jax.Array, k: int,
     """k-NN over all live rows. Returns (ids [nq,k] int64, scores [nq,k]).
 
     Missing results (fewer than k live rows) are (-1, INF).
-    ``use_kernel=True`` scores through Pallas qgemm and selects through
-    Pallas qtopk — bit-identical to the pure-jnp path
-    (tests/test_query_engine.py::test_kernel_parity).
+    ``use_kernel=True`` scores through the Pallas qgemm kernel instead of
+    ``limbs.exact_dot``, bit-identical either way
+    (tests/test_query_engine.py::test_kernel_parity). The select is
+    :func:`topk_by_score` on both, which picks its path from the platform
+    and the shapes.
     """
     scores = score_block(queries_raw, state.vectors, metric, use_kernel)
     with jax.named_scope("scan"):
         scores = jnp.where(state.valid[None, :], scores, INF)
     with jax.named_scope("select"):
-        # tombstoned ids are -1; give them +inf-ish id so they sort last
-        # among ties
-        ids = jnp.where(state.valid, state.ids, jnp.int64(1) << 62)
-    if use_kernel:
-        s, i = _topk_by_score_kernel(scores, ids, k)
-    else:
-        s, i = topk_by_score(scores, ids, k)
+        # tombstoned ids are -1; give each dead slot an id of its own past
+        # 2^62, so the ids the select breaks ties on stay unique
+        slots = jnp.arange(state.ids.shape[0], dtype=jnp.int64)
+        ids = jnp.where(state.valid, state.ids, (jnp.int64(1) << 62) + slots)
+    s, i = topk_by_score(scores, ids, k)
     found = s < INF
     return jnp.where(found, i, jnp.int64(-1)), jnp.where(found, s, INF)
 
@@ -177,10 +197,7 @@ def coarse_search(state: MemoryState, table, queries_raw: jax.Array, k: int,
     # set is deterministic; the *served* tie order is fixed later by the
     # exact (score, id) merge, the same combine every fan-in path shares
     slots = jnp.arange(n, dtype=jnp.int64)
-    if use_kernel:
-        s_c, slot_c = _topk_by_score_kernel(approx, slots, ef)
-    else:
-        s_c, slot_c = topk_by_score(approx, slots, ef)
+    s_c, slot_c = topk_by_score(approx, slots, ef)
     slot_i = slot_c.astype(jnp.int32)                       # [nq, ef]
 
     # exact re-rank: the same wide integer arithmetic as score_block over

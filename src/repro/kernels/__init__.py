@@ -11,7 +11,9 @@ across shape sweeps — integer kernels admit no tolerance.
               the exact scan (int32 rows) and the compressed tier's coarse
               scan (int32 query weights x int8 codes, 1/4 the bytes
               streamed)
-  qtopk     — deterministic k-smallest with tie keys over dual-plane scores
+  qtopk     — deterministic k smallest (score, key) per row, streaming a
+              running k best over int32 planes: the exact select on a
+              TPU (``core.search.topk_by_score``)
   qboundary — fused float→Q-encode→integer-L2-normalize (the paper's §5.3
               determinism boundary); on no serving path, kept with its
               interpret-mode tests

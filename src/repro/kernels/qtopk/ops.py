@@ -1,4 +1,11 @@
-"""jit'd public wrapper for qtopk: plane split, padding, final candidate merge."""
+"""jit'd public wrapper for qtopk: plane split, padding, the survivors' sort.
+
+This is the blockwise half of the one exact select,
+``core.search.topk_by_score``: on a TPU, for k <= 128 over rows of at
+least a few blocks, it replaces the full two-key sort of each row with one
+streaming pass of the kernel over the row's blocks and a sort of the k
+survivors.
+"""
 from __future__ import annotations
 
 import functools
@@ -17,7 +24,7 @@ _BIAS = 0x80000000
 
 
 def split_planes(scores: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """int64 scores → (hi int32, sign-biased lo int32); lex order preserved."""
+    """int64 values → (hi int32, sign-biased lo int32); lex order preserved."""
     s = scores.astype(jnp.int64)
     hi = (s >> 32).astype(jnp.int32)
     lo_u = (s & 0xFFFFFFFF).astype(jnp.uint32) ^ jnp.uint32(_BIAS)
@@ -30,6 +37,9 @@ def combine_planes(hi: jax.Array, lo: jax.Array) -> jax.Array:
     return (hi.astype(jnp.int64) << 32) | lo_u
 
 
+BLOCK_N = 1024  # scores per block along a row (fewer when the row is short)
+
+
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
@@ -39,33 +49,32 @@ def qtopk(scores: jax.Array, keys: jax.Array, k: int
           ) -> Tuple[jax.Array, jax.Array]:
     """Deterministic k smallest (score, key) per row.
 
-    scores [nq, n] int64 wide scores; keys [n] int32 tie keys (unique).
-    Returns (scores [nq, k] int64, keys [nq, k] int32), sorted.
+    scores [nq, n] int64 wide scores; keys [n] integer tie keys, unique;
+    k <= n. Returns (scores [nq, k] int64, keys [nq, k] int64), sorted.
     Bit-identical to ref.qtopk_ref.
     """
     nq, n = scores.shape
+    if k > n:
+        raise ValueError(f"qtopk needs k <= n (got k={k}, n={n})")
     bq = min(128, _round_up(nq, 8))
-    bn = min(1024, _round_up(n, _kernel.LANES))
+    bn = min(BLOCK_N, _round_up(n, _kernel.LANES))
     hi, lo = split_planes(scores)
+    key_hi, key_lo = split_planes(keys)
 
+    # padding is all-I32_MAX: it never beats a member of the running set
     pq = _round_up(nq, bq) - nq
     pn = _round_up(n, bn) - n
     fill = _kernel.I32_MAX
-    hi = jnp.pad(hi, ((0, pq), (0, pn)), constant_values=fill)
-    lo = jnp.pad(lo, ((0, pq), (0, pn)), constant_values=fill)
-    keys_p = jnp.pad(keys.astype(jnp.int32), (0, pn),
-                     constant_values=fill)[None, :]
+    hi, lo = (jnp.pad(p, ((0, pq), (0, pn)), constant_values=fill)
+              for p in (hi, lo))
+    key_hi, key_lo = (jnp.pad(p, (0, pn), constant_values=fill)[None, :]
+                      for p in (key_hi, key_lo))
 
-    kk = min(k, bn)
-    cands = on_platform(
-        functools.partial(_kernel.qtopk_pallas, k=kk, block_q=bq,
-                          block_n=bn), hi, lo, keys_p)
-    # drop each block's lane padding past its kk candidates
-    n_blocks = hi.shape[1] // bn
-    c_hi, c_lo, c_key = (
-        c[:nq].reshape(nq, n_blocks, -1)[:, :, :kk].reshape(nq, -1)
-        for c in cands)
-    # final merge over the per-block candidates (small): exact int64 sort
-    s, i = jax.lax.sort((combine_planes(c_hi, c_lo), c_key), num_keys=2,
-                        dimension=1)
-    return s[:, :k], i[:, :k]
+    best = on_platform(
+        functools.partial(_kernel.qtopk_pallas, k=k, block_q=bq,
+                          block_n=bn), hi, lo, key_hi, key_lo)
+    c_hi, c_lo, c_key_hi, c_key_lo = (c[:nq, :k] for c in best)
+    # the k survivors of each row, in (score, key) order
+    return jax.lax.sort((combine_planes(c_hi, c_lo),
+                         combine_planes(c_key_hi, c_key_lo)),
+                        num_keys=2, dimension=1)

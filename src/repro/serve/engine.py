@@ -102,7 +102,7 @@ class ServeConfig:
     # tables incrementally on ingest (table == codes.build(state) always)
     ef_coarse: int = 0
     exact_threshold: int = 1024  # live count at/below which exact scan wins
-    use_kernel: bool = False     # Pallas kernels on the exact/coarse routes
+    use_kernel: bool = False     # Pallas qgemm scan on the exact/coarse routes
     # durability (DESIGN.md §5): with a durable_dir, every ingested command
     # is WAL-appended before it is visible, incremental v2 snapshots are cut
     # every checkpoint_every commands (0 = manual only), and recover()

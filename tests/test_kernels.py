@@ -65,6 +65,12 @@ def test_qtopk_tie_break_by_key():
     assert np.asarray(got_k)[0].tolist() == [0, 1, 2, 3, 4]
 
 
+def test_qtopk_rejects_k_past_n():
+    s = np.zeros((2, 8), np.int64)
+    with pytest.raises(ValueError, match="k <= n"):
+        qtopk_ops.qtopk(jnp.asarray(s), jnp.arange(8), 9)
+
+
 def test_qtopk_big_block_sweep():
     for n in (1024, 2048, 4096, 5000):
         s = RNG.integers(-2**40, 2**40, size=(4, n)).astype(np.int64)

@@ -11,14 +11,19 @@ import subprocess
 import sys
 import textwrap
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _pbt import given, settings
 from _pbt import strategies as st
 
 import repro  # noqa: F401
 from repro.core import boundary, commands, hnsw, machine, query, search
 from repro.core.state import init_state
+from repro.kernels.qtopk import ops as qtopk_ops
 
 D = 20
 INF = int(search.INF)
@@ -228,6 +233,83 @@ def test_kernel_parity_l2_and_dot_odd_shapes():
                 (seed, metric)
             assert (np.asarray(got[1]) == np.asarray(ref[1])).all(), \
                 (seed, metric)
+
+
+# --------------------------------------------------------------------------- #
+# the one exact select: its blockwise branch (qtopk, interpreted on CPU) ==
+# the full sort it takes on the CPU, bit for bit
+# --------------------------------------------------------------------------- #
+
+BLOCK = qtopk_ops.BLOCK_N
+
+
+def _select_inputs(seed: int, nq: int, n: int, metric: str, n_dead: int = 0,
+                   n_live: int | None = None):
+    """Scores and ids as ``exact_search`` hands them to its select.
+
+    Rows come from a 5-letter alphabet in 4 dims (625 distinct vectors), so
+    exact score ties are everywhere; twelve equal rows straddle the first
+    block boundary and the first query is their vector, so under L2 its
+    ties at score 0 straddle the k-th place for k <= 10. Ids are a shuffled
+    stride (id order != slot order); dead rows score INF under 2^62 + slot.
+    """
+    rng = np.random.default_rng(seed)
+    vecs = rng.integers(-2, 3, size=(n, 4)).astype(np.int32) * 4096
+    edge = min(BLOCK, n) - 6
+    vecs[edge:edge + 12] = vecs[edge]
+    q = rng.integers(-2, 3, size=(nq, 4)).astype(np.int32) * 4096
+    q[0] = vecs[edge]
+    valid = np.ones(n, bool)
+    if n_live is not None:
+        valid[rng.permutation(n)[n_live:]] = False
+    valid[rng.choice(n, size=n_dead, replace=False)] = False
+    ids = rng.permutation(n).astype(np.int64) * 13 + 5
+    scores = search.score_block(jnp.asarray(q), jnp.asarray(vecs), metric)
+    scores = jnp.where(jnp.asarray(valid)[None, :], scores, search.INF)
+    ids = jnp.where(jnp.asarray(valid), jnp.asarray(ids),
+                    (jnp.int64(1) << 62) + jnp.arange(n, dtype=jnp.int64))
+    return scores, ids
+
+
+@pytest.mark.parametrize("metric", [search.METRIC_L2, search.METRIC_DOT])
+@pytest.mark.parametrize("seed,nq,n,k,n_dead,n_live", [
+    (0, 13, 2500, 10, 0, None),      # n not a multiple of the block
+    (1, 5, 2500, 1, 200, None),      # k = 1, tombstones
+    (2, 3, 1500, 128, 100, None),    # k = 128 over two blocks, one partial
+    (3, 13, 2100, 128, 0, 50),       # fewer live rows than k
+    (4, 8, 3 * 1024, 10, 40, None),  # n a multiple of the block
+    (5, 1, 700, 10, 7, None),        # one short block
+    (6, 130, 2100, 10, 30, None),    # two row blocks, the second padded
+])
+def test_blockwise_select_equals_full_sort(metric, seed, nq, n, k, n_dead,
+                                          n_live):
+    scores, ids = _select_inputs(seed, nq, n, metric, n_dead, n_live)
+    want = search.topk_by_score(scores, ids, k)  # on CPU: the full sort
+    got = qtopk_ops.qtopk(scores, ids, k)
+    assert (np.asarray(got[0]) == np.asarray(want[0])).all()
+    assert (np.asarray(got[1]) == np.asarray(want[1])).all()
+    if seed == 0 and metric == search.METRIC_L2:  # ties cut at the k-th place
+        assert (np.asarray(want[0])[0] == 0).all()
+
+
+@pytest.mark.parametrize("n,k,blockwise", [
+    (1 << 20, 10, True),      # the batch cell's select
+    (4 * BLOCK, 128, True),
+    (1 << 20, 129, False),    # k past 128
+    (1 << 20, 1024, False),   # the coarse route's candidate cut
+    (4 * BLOCK - 1, 10, True),  # the last block partial
+    (BLOCK, 10, True),        # one block
+    (BLOCK - 1, 10, False),   # a row shorter than a block
+    (64, 5, False),           # tiny n
+])
+def test_select_path_follows_shapes(n, k, blockwise):
+    """The blockwise branch is staged only where the shape rule admits it;
+    everywhere else the select is the full sort on every platform."""
+    assert search.blockwise_applies(n, k) == blockwise
+    jaxpr = jax.make_jaxpr(functools.partial(search.topk_by_score, k=k))(
+        jax.ShapeDtypeStruct((2, n), jnp.int64),
+        jax.ShapeDtypeStruct((n,), jnp.int64))
+    assert ("platform_index" in str(jaxpr)) == blockwise
 
 
 # --------------------------------------------------------------------------- #

@@ -5,13 +5,14 @@ described (not attached) ``v5e:2x2`` topology, which refuses what Mosaic or
 XLA would refuse on the chip — an s64 ``dot``, an unsupported operand dtype,
 a block that breaks the lane tiling, more VMEM than a kernel may use.
 Shapes are the substrate's real ones: d=768 rows, 2^17 arena rows, a batch
-of 128 queries, k=10.
+of 128 queries, k=10; and the batch cell's exact route, 2^20 rows of d=128.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and under several test
 workers every worker imports this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ from repro.core.state import init_state
 from repro.kernels.qgemm import kernel as qgemm_kernel
 from repro.kernels.qgemm import ops as qgemm_ops
 from repro.kernels.qtopk import kernel as qtopk_kernel
+from repro.kernels.qtopk import ops as qtopk_ops
 
 DIM = 768
 ROWS = 1 << 17
@@ -68,6 +70,26 @@ def _compile(fn, *args):
     return compiled.as_text()
 
 
+def _kernels(text):
+    """Names of the Pallas kernels (``tpu_custom_call``s) in compiled HLO:
+    the instruction takes the ``pallas_call``'s name."""
+    return {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)(?:\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
+        text)}
+
+
+def _sort_rows(text):
+    """The length along the sorted dimension of every sort in compiled
+    HLO, read from its first result's shape."""
+    rows = []
+    for line in text.splitlines():
+        m = re.search(r"= \(?[a-z0-9]+\[([0-9,]*)\].* sort\(.*"
+                      r"dimensions=\{([0-9]+)\}", line)
+        if m:
+            rows.append(int(m.group(1).split(",")[int(m.group(2))]))
+    return rows
+
+
 @pytest.mark.parametrize("b_dtype", [jnp.int32, jnp.int8],
                          ids=["qgemm", "qcoarse"])
 def test_digit_kernel_compiles(one_chip, b_dtype):
@@ -85,20 +107,49 @@ def test_qtopk_kernel_compiles(one_chip):
     fn = functools.partial(qtopk_kernel.qtopk_pallas, k=K, block_q=NQ,
                            block_n=1024, interpret=False)
     plane = _spec(one_chip, (NQ, ROWS), jnp.int32)
-    text = _compile(fn, plane, plane, _spec(one_chip, (1, ROWS), jnp.int32))
+    key = _spec(one_chip, (1, ROWS), jnp.int32)
+    text = _compile(fn, plane, plane, key, key)
     assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("use_kernel", [False, True],
                          ids=["limbs", "kernels"])
 def test_exact_route_compiles(one_chip, use_kernel):
-    """The default exact route (int8 digit dots in XLA) and the kernel
-    route; the kernel route lowers to Mosaic, not to the interpreter."""
+    """The default exact route (int8 digit dots in XLA) and the qgemm
+    route; both select blockwise through qtopk on a TPU (k <= 128 over many
+    blocks), and the kernels lower to Mosaic, not to the interpreter."""
     state = _on_chip(one_chip, jax.eval_shape(lambda: init_state(ROWS, DIM)))
     q = _spec(one_chip, (NQ, DIM), jnp.int32)
     fn = functools.partial(search.exact_search, k=K, use_kernel=use_kernel)
-    text = _compile(fn, state, q)
-    assert ("tpu_custom_call" in text) == use_kernel
+    kernels = _kernels(_compile(fn, state, q))
+    assert "qtopk" in kernels
+    assert ("qgemm" in kernels) == use_kernel
+
+
+def test_batch_cell_select_sorts_no_full_row(one_chip):
+    """The batch cell's exact route, 128 queries over 2^20 rows of d=128,
+    k=10: no sort of the compiled program is longer than the blockwise
+    merge's row bound, n_blocks * 128 (the full [128, 2^20] sort is gone)."""
+    rows, dim = 1 << 20, 128
+    state = _on_chip(one_chip, jax.eval_shape(lambda: init_state(rows, dim)))
+    q = _spec(one_chip, (NQ, dim), jnp.int32)
+    text = _compile(functools.partial(search.exact_search, k=K), state, q)
+    assert "qtopk" in _kernels(text)
+    sorts = _sort_rows(text)
+    assert sorts and max(sorts) <= (rows // qtopk_ops.BLOCK_N) * 128, sorts
+
+
+def test_open_cell_select_sorts_no_full_row(one_chip):
+    """The open cell's exact route, one query over 2^20 rows of d=768,
+    k=10: the row block pads to 8 and still selects through qtopk, with no
+    sort of the full row."""
+    rows = 1 << 20
+    state = _on_chip(one_chip, jax.eval_shape(lambda: init_state(rows, DIM)))
+    q = _spec(one_chip, (1, DIM), jnp.int32)
+    text = _compile(functools.partial(search.exact_search, k=K), state, q)
+    assert "qtopk" in _kernels(text)
+    sorts = _sort_rows(text)
+    assert sorts and max(sorts) <= (rows // qtopk_ops.BLOCK_N) * 128, sorts
 
 
 @pytest.mark.parametrize("use_kernel", [False, True],
