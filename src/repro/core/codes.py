@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import fixedpoint as fp
 from repro.core import hashing
 from repro.core.state import MemoryState
@@ -130,9 +131,17 @@ def encode_rows(vectors: jax.Array, valid: jax.Array,
     return c, norms
 
 
-@jax.jit
 def build(state: MemoryState) -> CodeTable:
-    """The reference constructor: the whole table from the live rows."""
+    """The reference constructor: the whole table from the live rows.
+    Each call counts one ``code_table_builds`` (``repro.obs``), wherever
+    it comes from: a caller, ``query.execute_plan`` without a table, or
+    ``refresh``'s fallback."""
+    obs.count("code_table_builds")
+    return _build(state)
+
+
+@jax.jit
+def _build(state: MemoryState) -> CodeTable:
     offset, scale = code_params(state.vectors, state.valid)
     c, norms = encode_rows(state.vectors, state.valid, offset, scale)
     return CodeTable(codes=c, offset=offset, scale=scale, norms=norms)

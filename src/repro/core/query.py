@@ -117,7 +117,11 @@ def plan_query(live_count: int, k: int, ef: int, *,
          bytes of the exact scan, so bytes beat exact once the re-rank
          pool is under 3/4 of the corpus (the break-even of
          live*dim*1 + ef*dim*4 vs live*dim*4); the dim cap is the qgemm
-         kernel's int32 exactness bound;
+         kernel's int32 exactness bound. The model counts bytes read
+         only: not the cut, which at ``ef_coarse`` > 128 is one sort of
+         the whole [nq, live] row (528.6 ms for [128, 2^20] on a TPU
+         v5e), nor the int32 digit planes both scans write (DESIGN.md
+         §10; the costs measured at 1M x 768: PERF.md §6);
       6. otherwise → HNSW — including under churn. Deletes no longer
          demote the graph to exact scan: entry-point repair keeps every
          layout's entry live and the scheduled re-link pass (recorded in

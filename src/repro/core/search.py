@@ -169,7 +169,10 @@ def coarse_search(state: MemoryState, table, queries_raw: jax.Array, k: int,
     bit-for-bit, which is the conformance suite's coarse-route contract.
 
     Returns (ids [nq, k] int64, scores [nq, k] int64); missing results
-    are (-1, INF), exactly like ``exact_search``.
+    are (-1, INF), exactly like ``exact_search``. Its ops carry one of
+    three scopes: ``code_scan`` (query weights, the int8 dot, norms and
+    the validity mask), ``select`` (the ``ef_coarse`` cut) and ``rerank``
+    (the gather of [nq, ef, d] rows, the exact re-score and the merge).
     """
     from repro.core import codes as codes_lib    # lazy: codes is leaf-level
 
@@ -181,36 +184,39 @@ def coarse_search(state: MemoryState, table, queries_raw: jax.Array, k: int,
             f"k={k}, capacity={n}): a candidate set of {ef} cannot "
             f"yield {k} results")
 
-    w = codes_lib.query_weights(queries_raw, table, metric)
-    if use_kernel:
-        from repro.kernels.qgemm import ops as qgemm_ops
-        s = qgemm_ops.qgemm(w, table.codes)
-    else:
-        s = limbs.exact_dot(w, table.codes)
-    if metric == METRIC_L2:
-        approx = table.norms[None, :] - 2 * s
-    else:
-        approx = -s
-    approx = jnp.where(state.valid[None, :], approx, INF)
+    with jax.named_scope("code_scan"):
+        w = codes_lib.query_weights(queries_raw, table, metric)
+        if use_kernel:
+            from repro.kernels.qgemm import ops as qgemm_ops
+            s = qgemm_ops.qgemm(w, table.codes)
+        else:
+            s = limbs.exact_dot(w, table.codes)
+        if metric == METRIC_L2:
+            approx = table.norms[None, :] - 2 * s
+        else:
+            approx = -s
+        approx = jnp.where(state.valid[None, :], approx, INF)
 
     # candidate selection by (approx score, slot): slots are unique, so the
     # set is deterministic; the *served* tie order is fixed later by the
     # exact (score, id) merge, the same combine every fan-in path shares
-    slots = jnp.arange(n, dtype=jnp.int64)
-    s_c, slot_c = topk_by_score(approx, slots, ef)
-    slot_i = slot_c.astype(jnp.int32)                       # [nq, ef]
+    with jax.named_scope("select"):
+        slots = jnp.arange(n, dtype=jnp.int64)
+        s_c, slot_c = topk_by_score(approx, slots, ef)
 
     # exact re-rank: the same wide integer arithmetic as score_block over
     # the full arena, gathered per query (integer sums are order-invariant,
     # so the values are bit-identical to the full scan's)
-    rows = state.vectors[slot_i]                            # [nq, ef, d]
-    exact = jax.vmap(
-        lambda q, db: score_block(q[None, :], db, metric)[0]
-    )(queries_raw, rows)                                    # [nq, ef]
-    live = state.valid[slot_i] & (s_c < INF)
-    exact = jnp.where(live, exact, INF)
-    cand_ids = jnp.where(live, state.ids[slot_i], jnp.int64(1) << 62)
-    s_out, i_out = merge_candidates(exact, cand_ids, k)
+    with jax.named_scope("rerank"):
+        slot_i = slot_c.astype(jnp.int32)                   # [nq, ef]
+        rows = state.vectors[slot_i]                        # [nq, ef, d]
+        exact = jax.vmap(
+            lambda q, db: score_block(q[None, :], db, metric)[0]
+        )(queries_raw, rows)                                # [nq, ef]
+        live = state.valid[slot_i] & (s_c < INF)
+        exact = jnp.where(live, exact, INF)
+        cand_ids = jnp.where(live, state.ids[slot_i], jnp.int64(1) << 62)
+        s_out, i_out = merge_candidates(exact, cand_ids, k)
     return i_out, s_out
 
 

@@ -7,6 +7,7 @@ loop over ``hnsw.hnsw_search`` / ``search.exact_search``. Randomized logs
 ``merge_topk``'s algebraic properties get a property test via ``_pbt``.
 """
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -310,6 +311,54 @@ def test_select_path_follows_shapes(n, k, blockwise):
         jax.ShapeDtypeStruct((2, n), jnp.int64),
         jax.ShapeDtypeStruct((n,), jnp.int64))
     assert ("platform_index" in str(jaxpr)) == blockwise
+
+
+# --------------------------------------------------------------------------- #
+# the coarse route: its scopes and the count of whole-table builds
+# --------------------------------------------------------------------------- #
+
+_COARSE_SCOPES = ("code_scan", "select", "rerank")
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_coarse_program_ops_lie_under_one_scope_each(use_kernel):
+    """Every op of the compiled coarse program that comes from
+    ``coarse_search`` lies under exactly one of its three scopes."""
+    from repro.core import codes
+    state, _ = _random_state(4)
+    table = codes.build(state)
+    text = search.coarse_search.lower(
+        state, table, _queries(4), 5, ef_coarse=32,
+        use_kernel=use_kernel).compile().as_text()
+    paths = re.findall(r'op_name="(jit\(coarse_search\)/[^"]*)"', text)
+    assert paths
+    for scope in _COARSE_SCOPES:
+        assert any(scope in p.split("/")[:-1] for p in paths), scope
+    for p in paths:
+        parts = p.split("/")[:-1]
+        assert sum(s in parts for s in _COARSE_SCOPES) == 1, p
+
+
+def test_code_table_builds_counts_whole_table_builds():
+    """``execute_plan`` without a table builds one and counts it; with the
+    caller's table it builds none."""
+    from repro import obs
+    from repro.core import codes
+
+    def builds():
+        return sum(obs.counters().get("code_table_builds", {}).values())
+
+    state, _ = _random_state(5)
+    q = _queries(5)
+    plan = query.plan_query(110, 5, 16, route="coarse", ef_coarse=32, dim=D)
+    table = codes.build(state)
+    before = builds()
+    with_table = query.execute_plan(state, q, 5, plan, codes=table)
+    assert builds() == before
+    on_the_spot = query.execute_plan(state, q, 5, plan)
+    assert builds() == before + 1
+    for a, b in zip(with_table, on_the_spot):
+        assert (np.asarray(a) == np.asarray(b)).all()
 
 
 # --------------------------------------------------------------------------- #

@@ -163,3 +163,22 @@ def test_coarse_route_compiles(one_chip, use_kernel):
                            use_kernel=use_kernel)
     text = _compile(fn, state, table, q)
     assert ("tpu_custom_call" in text) == use_kernel
+
+
+def test_coarse_cell_cuts_with_one_sort_of_the_full_row(one_chip):
+    """The coarse cell's program, 128 queries over 2^20 rows of d=768 with
+    ef_coarse 1,024: the cut takes the sort side of the select rule (k past
+    128), one sort of the whole row of 2^20; the only other sort is the
+    re-rank's merge of [128, 1,024]. Its ops carry the three scopes the
+    benchmark reads."""
+    rows = 1 << 20
+    shapes = jax.eval_shape(lambda: init_state(rows, DIM))
+    state = _on_chip(one_chip, shapes)
+    table = _on_chip(one_chip, jax.eval_shape(codes.build, shapes))
+    q = _spec(one_chip, (NQ, DIM), jnp.int32)
+    fn = functools.partial(search.coarse_search, k=K, ef_coarse=1024)
+    text = _compile(fn, state, table, q)
+    assert sorted(_sort_rows(text)) == [1024, rows]
+    for scope in ("code_scan", "select", "rerank"):
+        assert re.search(
+            rf'op_name="[^"]*jit\(coarse_search\)/([^"]*/)?{scope}/', text), scope
